@@ -58,6 +58,9 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         backends,
         federation,

@@ -27,6 +27,12 @@ Run it::
     PYTHONPATH=src python examples/replica_readers.py --smoke    # CI-sized
 
 Exit code 0 = every invariant held in every process.
+
+One process per accelerator: the build and the writer's inserts run jitted
+JAX code, and a chip belongs to the first process that touches it.  So the
+parent never imports JAX: a spawned child builds the blob and exits before
+the writer starts, and the readers' plain searches stay in numpy (each
+reader checks that it never initialised a JAX backend).
 """
 from __future__ import annotations
 
@@ -145,21 +151,34 @@ def reader_proc(
                 time.sleep(poll_s)
     finally:
         os.close(fd)
+    from jax._src import xla_bridge
+
+    assert not xla_bridge.backends_are_initialized(), "a reader took the writer's chip"
+
+
+# ------------------------------------------------------------------- build
+def build_proc(root: str) -> None:
+    from repro.core import ECPBuildConfig, build_index, convert
+    from repro.data import clustered_vectors
+
+    data, _ = clustered_vectors(0, n=1500, dim=DIM, n_clusters=12)
+    build_index(data, f"{root}/idx", ECPBuildConfig(levels=2, cluster_cap=64))
+    convert(f"{root}/idx", f"{root}/index.blob")
 
 
 # ----------------------------------------------------------------- harness
 def run(n_readers: int = 3, n_rounds: int = 12, batch: int = 32) -> dict:
     import tempfile
 
-    from repro.core import ECPBuildConfig, build_index, convert
-    from repro.data import clustered_vectors
-
-    data, _ = clustered_vectors(0, n=1500, dim=DIM, n_clusters=12)
     ctx = mp.get_context("spawn")  # clean children: no inherited locks/fds
     with tempfile.TemporaryDirectory() as td:
         td = Path(td)
-        build_index(data, str(td / "idx"), ECPBuildConfig(levels=2, cluster_cap=64))
-        blob = str(convert(str(td / "idx"), td / "index.blob"))
+        builder = ctx.Process(target=build_proc, args=(str(td),))
+        builder.start()
+        builder.join(timeout=120)
+        assert builder.exitcode == 0, f"build failed: exit {builder.exitcode}"
+        blob = str(td / "index.blob")
+        assert "jax" not in sys.modules, "the parent must leave the chip to the writer"
         stop = str(td / "STOP")
         wlog = str(td / "published.log")
         rlogs = [str(td / f"reader_{i}.log") for i in range(n_readers)]

@@ -35,7 +35,9 @@ from .packed import PackedIndex
 
 __all__ = ["BatchedQuery", "BatchedQueryState", "BatchedSearcher"]
 
-_INF = jnp.float32(jnp.inf)
+# numpy, not jnp: a jnp constant at import would take the accelerator in
+# every process that imports repro.core, even one that never searches here
+_INF = np.float32(np.inf)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -59,6 +61,76 @@ def _ascending_top_k(d, ids, k):
     """Smallest-k by distance; returns (d_k, ids_k) ascending."""
     neg, idx = jax.lax.top_k(-d, k)
     return -neg, jnp.take_along_axis(ids, idx, axis=-1)
+
+
+# The jitted stages take the packed hierarchy (``BatchedSearcher.arrays``)
+# as an ARGUMENT: a jit that closed over it would embed the whole index as
+# constants in every compiled program, which the host copies while
+# compiling.  As arguments, one compiled program also serves every
+# searcher whose arrays have the same shapes.
+@partial(jax.jit, static_argnames=("metric", "b_internal"))
+def rank_leaves(arrays: dict, q: jnp.ndarray, *, metric: str, b_internal: int):
+    """[B, D] queries -> ranked candidate leaves [B, R] (+ distances)."""
+    B = q.shape[0]
+    d = jnp_distances(q, arrays["root"], metric)           # [B, n1]
+    n1 = d.shape[-1]
+    int_levels = list(zip(arrays["int_emb"], arrays["int_ids"], arrays["int_mask"]))
+    if not int_levels:  # L == 1: root children are the leaves
+        order = jnp.argsort(d, axis=-1)
+        return order.astype(jnp.int32), jnp.take_along_axis(d, order, axis=-1)
+    b = min(b_internal, n1)
+    node_d, node = _ascending_top_k(d, jnp.broadcast_to(jnp.arange(n1, dtype=jnp.int32), d.shape), b)
+    for li, (emb, ids, mask) in enumerate(int_levels):
+        ce = emb[node]                                      # [B, b, maxc, D]
+        cd = jnp_distances(q[:, None, None, :], ce, metric)[:, :, 0, :]  # [B, b, maxc]
+        cm = mask[node]
+        cd = jnp.where(cm, cd, _INF)
+        cid = jnp.where(cm, ids[node], -1)
+        flat_d = cd.reshape(B, -1)
+        flat_i = cid.reshape(B, -1)
+        if li == len(int_levels) - 1:
+            order = jnp.argsort(flat_d, axis=-1)            # rank ALL leaves seen
+            return (
+                jnp.take_along_axis(flat_i, order, axis=-1).astype(jnp.int32),
+                jnp.take_along_axis(flat_d, order, axis=-1),
+            )
+        bb = min(b_internal, flat_d.shape[-1])
+        node_d, node = _ascending_top_k(flat_d, flat_i, bb)
+        node = jnp.maximum(node, 0)                        # guard -1 pads
+    raise AssertionError("unreachable")
+
+
+@partial(jax.jit, static_argnames=("metric", "b"))
+def scan_chunk(arrays: dict, q, state: BatchedQueryState, *, metric: str, b: int):
+    """Visit the next ``b`` ranked leaves; merge items into the buffer."""
+    B = q.shape[0]
+    R = state.leaf_rank.shape[1]
+    pos = state.next_ptr[:, None] + jnp.arange(b)[None, :]          # [B, b]
+    valid = pos < R
+    pos_c = jnp.minimum(pos, R - 1)
+    leaf = jnp.take_along_axis(state.leaf_rank, pos_c, axis=-1)     # [B, b]
+    lvalid = valid & (leaf >= 0)
+    leaf_c = jnp.maximum(leaf, 0)
+    emb = arrays["leaf_emb"][leaf_c]                                # [B, b, cap, D]
+    ids = arrays["leaf_ids"][leaf_c]                                # [B, b, cap]
+    mask = arrays["leaf_mask"][leaf_c] & lvalid[..., None]
+    cap = emb.shape[2]
+    c = emb.reshape(B, b * cap, -1)
+    d = jnp_distances(q[:, None, :], c, metric)[:, 0, :]            # [B, b*cap]
+    d = jnp.where(mask.reshape(B, -1), d, _INF)
+    i = jnp.where(mask.reshape(B, -1), ids.reshape(B, -1), -1)
+    # merge with buffer, re-sort, keep best C
+    C = state.buf_d.shape[1]
+    all_d = jnp.concatenate([state.buf_d, d], axis=-1)
+    all_i = jnp.concatenate([state.buf_i, i], axis=-1)
+    buf_d, buf_i = _ascending_top_k(all_d, all_i, C)
+    return BatchedQueryState(
+        leaf_rank=state.leaf_rank,
+        leaf_rank_d=state.leaf_rank_d,
+        next_ptr=state.next_ptr + b,
+        buf_d=buf_d,
+        buf_i=buf_i,
+    )
 
 
 class BatchedQuery(Query):
@@ -91,90 +163,19 @@ class BatchedSearcher:
     """Device-resident packed index + jitted search stages (the ``Searcher``
     for packed mode)."""
 
-    def __init__(self, packed: PackedIndex, *, scorer=None):
+    def __init__(self, packed: PackedIndex):
         self.info = packed.info
         self.metric = packed.info.metric
-        self.root = jnp.asarray(packed.root_emb)
-        self.int_emb = [jnp.asarray(p.emb) for p in packed.levels[:-1]]
-        self.int_ids = [jnp.asarray(p.ids) for p in packed.levels[:-1]]
-        self.int_mask = [jnp.asarray(p.mask) for p in packed.levels[:-1]]
         leaf = packed.leaf
-        self.leaf_emb = jnp.asarray(leaf.emb)
-        self.leaf_ids = jnp.asarray(leaf.ids)
-        self.leaf_mask = jnp.asarray(leaf.mask)
-        # scorer(q[B,D], c[B,N,D]) -> [B,N] distances; pluggable so the
-        # Pallas distance kernel can be swapped in (kernels/distance_topk).
-        self._scorer = scorer
-
-    # ---------------------------------------------------------------- util
-    def _score(self, q, c):
-        if self._scorer is not None:
-            return self._scorer(q, c)
-        return jnp_distances(q[:, None, :], c, self.metric)[:, 0, :] if c.ndim == 3 else jnp_distances(q, c, self.metric)
-
-    # ------------------------------------------------------------- stage 1
-    @partial(jax.jit, static_argnames=("self", "b_internal"))
-    def rank_leaves(self, q: jnp.ndarray, b_internal: int):
-        """[B, D] queries -> ranked candidate leaves [B, R] (+ distances)."""
-        B = q.shape[0]
-        d = jnp_distances(q, self.root, self.metric)           # [B, n1]
-        n1 = d.shape[-1]
-        if not self.int_emb:  # L == 1: root children are the leaves
-            order = jnp.argsort(d, axis=-1)
-            return order.astype(jnp.int32), jnp.take_along_axis(d, order, axis=-1)
-        b = min(b_internal, n1)
-        node_d, node = _ascending_top_k(d, jnp.broadcast_to(jnp.arange(n1, dtype=jnp.int32), d.shape), b)
-        for li, (emb, ids, mask) in enumerate(zip(self.int_emb, self.int_ids, self.int_mask)):
-            ce = emb[node]                                      # [B, b, maxc, D]
-            cd = jnp_distances(q[:, None, None, :], ce, self.metric)[:, :, 0, :]  # [B, b, maxc]
-            cm = mask[node]
-            cd = jnp.where(cm, cd, _INF)
-            cid = jnp.where(cm, ids[node], -1)
-            flat_d = cd.reshape(B, -1)
-            flat_i = cid.reshape(B, -1)
-            is_last = li == len(self.int_emb) - 1
-            if is_last:
-                order = jnp.argsort(flat_d, axis=-1)            # rank ALL leaves seen
-                return (
-                    jnp.take_along_axis(flat_i, order, axis=-1).astype(jnp.int32),
-                    jnp.take_along_axis(flat_d, order, axis=-1),
-                )
-            bb = min(b_internal, flat_d.shape[-1])
-            node_d, node = _ascending_top_k(flat_d, flat_i, bb)
-            node = jnp.maximum(node, 0)                        # guard -1 pads
-        raise AssertionError("unreachable")
-
-    # ------------------------------------------------------------- stage 2
-    @partial(jax.jit, static_argnames=("self", "b"))
-    def _scan_chunk(self, q, state: BatchedQueryState, b: int):
-        """Visit the next ``b`` ranked leaves; merge items into the buffer."""
-        B = q.shape[0]
-        R = state.leaf_rank.shape[1]
-        pos = state.next_ptr[:, None] + jnp.arange(b)[None, :]          # [B, b]
-        valid = pos < R
-        pos_c = jnp.minimum(pos, R - 1)
-        leaf = jnp.take_along_axis(state.leaf_rank, pos_c, axis=-1)     # [B, b]
-        lvalid = valid & (leaf >= 0)
-        leaf_c = jnp.maximum(leaf, 0)
-        emb = self.leaf_emb[leaf_c]                                     # [B, b, cap, D]
-        ids = self.leaf_ids[leaf_c]                                     # [B, b, cap]
-        mask = self.leaf_mask[leaf_c] & lvalid[..., None]
-        cap = emb.shape[2]
-        d = self._score(q, emb.reshape(B, b * cap, -1))                  # [B, b*cap]
-        d = jnp.where(mask.reshape(B, -1), d, _INF)
-        i = jnp.where(mask.reshape(B, -1), ids.reshape(B, -1), -1)
-        # merge with buffer, re-sort, keep best C
-        C = state.buf_d.shape[1]
-        all_d = jnp.concatenate([state.buf_d, d], axis=-1)
-        all_i = jnp.concatenate([state.buf_i, i], axis=-1)
-        buf_d, buf_i = _ascending_top_k(all_d, all_i, C)
-        return BatchedQueryState(
-            leaf_rank=state.leaf_rank,
-            leaf_rank_d=state.leaf_rank_d,
-            next_ptr=state.next_ptr + b,
-            buf_d=buf_d,
-            buf_i=buf_i,
-        )
+        self.arrays = {
+            "root": jnp.asarray(packed.root_emb),
+            "int_emb": [jnp.asarray(p.emb) for p in packed.levels[:-1]],
+            "int_ids": [jnp.asarray(p.ids) for p in packed.levels[:-1]],
+            "int_mask": [jnp.asarray(p.mask) for p in packed.levels[:-1]],
+            "leaf_emb": jnp.asarray(leaf.emb),
+            "leaf_ids": jnp.asarray(leaf.ids),
+            "leaf_mask": jnp.asarray(leaf.mask),
+        }
 
     @partial(jax.jit, static_argnames=("self", "k"))
     def _emit(self, state: BatchedQueryState, k: int):
@@ -204,7 +205,7 @@ class BatchedSearcher:
             q = q[None, :]
         B = q.shape[0]
         bi = b_internal if b_internal is not None else max(b, 8)
-        leaf_rank, leaf_rank_d = self.rank_leaves(q, bi)
+        leaf_rank, leaf_rank_d = rank_leaves(self.arrays, q, metric=self.metric, b_internal=bi)
         C = buffer_cap if buffer_cap is not None else max(4 * k, 256)
         state = BatchedQueryState(
             leaf_rank=leaf_rank,
@@ -213,9 +214,12 @@ class BatchedSearcher:
             buf_d=jnp.full((B, C), _INF),
             buf_i=jnp.full((B, C), -1, jnp.int32),
         )
-        state = self._scan_chunk(q, state, min(b, leaf_rank.shape[1]))
+        state = self._scan(q, state, min(b, leaf_rank.shape[1]))
         d, i, state = self._advance(q, state, k, b)
         return self._result(d, i, state, single, BatchedQuery(self, q, state, b=b, single=single))
+
+    def _scan(self, q, state: BatchedQueryState, b: int) -> BatchedQueryState:
+        return scan_chunk(self.arrays, q, state, metric=self.metric, b=b)
 
     def _advance(self, q: jnp.ndarray, state: BatchedQueryState, k: int, b: int):
         """Emit the next k items, scanning further leaves if needed."""
@@ -226,7 +230,7 @@ class BatchedSearcher:
             exhausted = state.next_ptr >= R
             if bool(jnp.all((have >= k) | exhausted)):
                 break
-            state = self._scan_chunk(q, state, min(b, R))
+            state = self._scan(q, state, min(b, R))
         return self._emit(state, k)
 
     def _result(self, d, i, state: BatchedQueryState, single: bool, query) -> ResultSet:

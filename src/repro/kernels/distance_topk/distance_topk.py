@@ -12,8 +12,11 @@ Layout / tiling:
     q @ cᵀ with f32 accumulation (preferred_element_type).
   * bq/bn default 128 — MXU-aligned (multiples of 128 on both matmul dims).
   * selection is a k-step masked-argmin extraction over the concatenated
-    [bq, k + bn] candidates — pure VPU ops (min/compare/cumsum), no
-    unsupported sort/top_k primitives inside the kernel.
+    [bq, k + bn] candidates — pure VPU ops (min/compare/iota); no
+    sort/top_k, and no cumsum, which Mosaic does not lower.
+  * the matmul runs at ``Precision.HIGHEST`` (f32 contraction): the
+    quantized scan's pruning bounds (core/quant.py) assume f32-exact
+    distances to the decoded rows, which a bf16 pass would break.
 
 VMEM budget at defaults (D=1152, bq=bn=128, k=128):
   q 128·1152·4 = 576 KB, c 576 KB, scores 64 KB, scratch 2·64 KB ≈ 1.4 MB.
@@ -27,9 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_ONE = -1
 
 
@@ -37,14 +37,17 @@ def _merge_topk(md, mi, k):
     """k-step extraction of the k smallest (value, id) pairs.
 
     md: [bq, M] distances, mi: [bq, M] int32 ids. Ties resolved to the
-    first (lowest position ⇒ lowest candidate index) via a cumsum mask.
+    first (lowest position ⇒ lowest candidate index): the smallest
+    position holding the minimum, found as a masked min over an iota.
     Returns ([bq, k], [bq, k]) ascending.
     """
+    pos = jax.lax.broadcasted_iota(jnp.int32, md.shape, 1)
+    n_pos = md.shape[1]
     out_d, out_i = [], []
     for _ in range(k):
         m = jnp.min(md, axis=1, keepdims=True)                  # [bq, 1]
-        is_min = md == m
-        first = is_min & (jnp.cumsum(is_min.astype(jnp.int32), axis=1) == 1)
+        first_pos = jnp.min(jnp.where(md == m, pos, n_pos), axis=1, keepdims=True)
+        first = pos == first_pos
         sel_i = jnp.sum(jnp.where(first, mi, 0), axis=1)        # unique hit
         out_d.append(m[:, 0])
         out_i.append(sel_i)
@@ -66,7 +69,9 @@ def _kernel(q_ref, c_ref, out_d_ref, out_i_ref, run_d, run_i, *, k, bn, n_total,
         q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-12)
         c = c * jax.lax.rsqrt(jnp.sum(c * c, -1, keepdims=True) + 1e-12)
     scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )                                                           # [bq, bn] MXU
     if metric == "ip":
         d = -scores
@@ -140,7 +145,7 @@ def distance_topk_pallas(
             pltpu.VMEM((bq, k), jnp.float32),
             pltpu.VMEM((bq, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

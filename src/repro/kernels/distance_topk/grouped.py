@@ -8,11 +8,21 @@ independent (grid axis 0 is parallel); the candidate axis reuses the
 running-top-k scratch pattern of ``distance_topk``.
 
 Inputs are padded to a common leaf size: codes [G, N_pad, D] in the
-quantized dtype (int8 | float16), per-group dequant params [G, 2]
-(scale, offset — f32, exactly as the blob companion stores them) and
-per-group valid row counts [G, 1] (int32).  Dequantization happens
-in-kernel right before the MXU, so HBM only ever holds the compressed
-codes — the whole point of the quantized scan.
+quantized dtype (int8 | float16), per-group dequant params (scale,
+offset — f32, exactly as the blob companion stores them) and per-group
+valid row counts (int32).  int8 dequantization happens in-kernel right
+before the MXU, so HBM only holds the compressed codes.  float16 codes
+are widened to f32 before the ``pallas_call`` (the cast is exact; TPU
+v5e's Mosaic cannot load f16 vectors), so they cross PCIe as f16 but
+sit in HBM as f32.
+
+Every per-group array carries a leading group axis that the BlockSpecs
+squeeze (``None``), so each block's last two dims equal the array's —
+what the TPU's (8, 128) tiling rule requires of a one-row block.
+
+The host wrapper pads N to a multiple of ``bn`` and G to a power of two
+before the jitted call: a traversal round's leaf sizes and unit counts
+vary, and each distinct shape would otherwise be a fresh compile.
 """
 from __future__ import annotations
 
@@ -20,10 +30,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .distance_topk import _CompilerParams, _merge_topk
+from .distance_topk import _merge_topk
 
 NEG_ONE = -1
 
@@ -40,15 +51,17 @@ def _gkernel(
         run_i[...] = jnp.full(run_i.shape, NEG_ONE, run_i.dtype)
 
     q = q_ref[...].astype(jnp.float32)                          # [1, D]
-    c = c_ref[0].astype(jnp.float32)                            # [bn, D]
+    c = c_ref[...].astype(jnp.float32)                          # [bn, D]
     if qformat == "int8":
         c = c * prm_ref[0, 0] + prm_ref[0, 1]                   # dequant on VPU
-    # float16 codes ARE the (cast) rows: astype above is the full decode
+    # float16 codes ARE the (cast) rows: the f32 widening is the full decode
     if metric == "cosine":
         q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-12)
         c = c * jax.lax.rsqrt(jnp.sum(c * c, -1, keepdims=True) + 1e-12)
     scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # f32: distance_bounds assume it
+        preferred_element_type=jnp.float32,
     )                                                           # [1, bn] MXU
     if metric == "ip":
         d = -scores
@@ -85,32 +98,11 @@ def _gkernel(
 @functools.partial(
     jax.jit, static_argnames=("k", "metric", "qformat", "bn", "interpret")
 )
-def grouped_distance_topk_pallas(
-    q: jnp.ndarray,
-    codes: jnp.ndarray,
-    scales: jnp.ndarray,
-    offsets: jnp.ndarray,
-    n_rows: jnp.ndarray,
-    k: int,
-    metric: str = "l2",
-    qformat: str = "int8",
-    *,
-    bn: int = 128,
-    interpret: bool = False,
-):
-    """q [G, D], codes [G, N_pad, D] (int8|f16), scales/offsets [G],
-    n_rows [G] -> (dists [G, k] f32, idx [G, k] i32) ascending; rows past
-    each group's n_rows come back as (inf, -1)."""
-    G, D = q.shape
-    N = codes.shape[1]
-    N_pad = -(-max(N, 1) // bn) * bn
-    if N_pad != N:
-        codes = jnp.pad(codes, ((0, 0), (0, N_pad - N), (0, 0)))
-    n_steps = N_pad // bn
-    prm = jnp.stack(
-        [jnp.asarray(scales, jnp.float32), jnp.asarray(offsets, jnp.float32)], axis=1
-    )                                                           # [G, 2]
-    nr = jnp.asarray(n_rows, jnp.int32)[:, None]                # [G, 1]
+def _grouped_call(q, codes, prm, nr, k, metric, qformat, bn, interpret):
+    G, N, D = codes.shape
+    n_steps = N // bn
+    if codes.dtype == jnp.float16:
+        codes = codes.astype(jnp.float32)
     kern = functools.partial(
         _gkernel, k=k, bn=bn, n_steps=n_steps, metric=metric, qformat=qformat
     )
@@ -118,26 +110,62 @@ def grouped_distance_topk_pallas(
         kern,
         grid=(G, n_steps),
         in_specs=[
-            pl.BlockSpec((1, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bn, D), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((None, 1, D), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, bn, D), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, 1, 2), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((None, 1, k), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, k), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((G, k), jnp.float32),
-            jax.ShapeDtypeStruct((G, k), jnp.int32),
+            jax.ShapeDtypeStruct((G, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((G, 1, k), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, k), jnp.float32),
             pltpu.VMEM((1, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(q, codes, prm, nr)
-    return out_d, out_i
+    return out_d[:, 0], out_i[:, 0]
+
+
+def grouped_distance_topk_pallas(
+    q,
+    codes,
+    scales,
+    offsets,
+    n_rows,
+    k: int,
+    metric: str = "l2",
+    qformat: str = "int8",
+    *,
+    bn: int = 128,
+    interpret: bool = False,
+):
+    """q [G, D], codes [G, N, D] (int8|f16), scales/offsets [G],
+    n_rows [G] -> (dists [G, k] f32, idx [G, k] i32) ascending; rows past
+    each group's n_rows come back as (inf, -1)."""
+    q = np.asarray(q, np.float32)
+    codes = np.asarray(codes)
+    G, N, D = codes.shape
+    G_pad = 1 << max(G - 1, 0).bit_length()
+    N_pad = -(-max(N, 1) // bn) * bn
+    if (G_pad, N_pad) != (G, N):
+        padded = np.zeros((G_pad, N_pad, D), codes.dtype)
+        padded[:G, :N] = codes
+        codes = padded
+    q3 = np.zeros((G_pad, 1, D), np.float32)
+    q3[:G, 0] = q
+    prm = np.zeros((G_pad, 1, 2), np.float32)
+    prm[:G, 0, 0] = scales
+    prm[:G, 0, 1] = offsets
+    nr = np.zeros((G_pad, 1, 1), np.int32)  # pad groups have no valid rows
+    nr[:G, 0, 0] = n_rows
+    out_d, out_i = _grouped_call(q3, codes, prm, nr, k, metric, qformat, bn, interpret)
+    return out_d[:G], out_i[:G]

@@ -1,8 +1,9 @@
 """Public op: distance_topk — jit'd wrapper choosing kernel vs reference.
 
-On TPU the Pallas kernel runs compiled; in this CPU container it is
-validated with ``interpret=True``. ``impl="auto"`` uses the reference path
-on CPU (fast) and the kernel on TPU, so callers never branch themselves.
+On TPU the Pallas kernel runs compiled; on CPU it is validated with
+``interpret=True``. ``impl="auto"`` uses the reference path on CPU (fast)
+and the compiled kernel on TPU, so callers never branch themselves;
+``resolve_impl`` says which one a call will take.
 """
 from __future__ import annotations
 
@@ -13,14 +14,21 @@ from .grouped import grouped_distance_topk_pallas
 from .ref import distance_topk_ref, grouped_distance_topk_ref
 
 
+def resolve_impl(impl: str = "auto") -> str:
+    """The implementation ``impl`` runs as: "auto" is the compiled Pallas
+    kernel ("pallas") on a TPU and the numpy/jnp reference ("ref")
+    elsewhere; any other value is taken as given."""
+    if impl == "auto":
+        return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
+    return impl
+
+
 def distance_topk(q, c, k: int, metric: str = "l2", *, impl: str = "auto", **kw):
     """q [B, D], c [N, D] -> (dists [B, k], idx [B, k]), ascending distance.
 
     impl: "auto" | "ref" | "pallas" | "pallas_interpret"
     """
-    if impl == "auto":
-        platform = jax.devices()[0].platform
-        impl = "pallas" if platform == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "ref":
         return distance_topk_ref(q, c, k, metric)
     if impl == "pallas":
@@ -51,9 +59,7 @@ def grouped_distance_topk(
     """
     import numpy as np
 
-    if impl == "auto":
-        platform = jax.devices()[0].platform
-        impl = "pallas" if platform == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "ref":
         d, i = grouped_distance_topk_ref(
             q, codes, scales, offsets, n_rows, k, metric, qformat

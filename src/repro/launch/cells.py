@@ -24,7 +24,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import arch_shapes, get_arch
-from repro.launch import mesh as mesh_lib
 from repro.models import abstract_params, gnn, param_count, param_pspecs, recsys
 from repro.models import transformer as T
 from repro.models.base import init_params
@@ -451,5 +450,5 @@ def lower_cell(cell: Cell, mesh):
             is_leaf=lambda x: isinstance(x, P) or x is None,
         )
     jf = jax.jit(cell.fn, in_shardings=in_shardings, donate_argnums=cell.donate, **kw)
-    with mesh_lib.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         return jf.lower(*cell.args)

@@ -16,52 +16,7 @@ __all__ = [
     "make_production_mesh",
     "make_host_mesh",
     "batch_axes_of",
-    "set_mesh",
-    "get_abstract_mesh",
-    "shard_map",
 ]
-
-
-def set_mesh(mesh):
-    """Version-compat mesh activation: ``with set_mesh(mesh): ...``.
-
-    jax >= 0.5 exposes ``jax.sharding.set_mesh`` (also usable as a context
-    manager); some 0.4.x releases only have ``jax.sharding.use_mesh``; on
-    anything older, ``Mesh`` itself is the context manager.  All call sites
-    in this repo (and its tests) go through this helper.
-    """
-    fn = getattr(jax.sharding, "set_mesh", None) or getattr(jax.sharding, "use_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
-def get_abstract_mesh():
-    """Version-compat read of the ambient mesh set by ``set_mesh``.
-
-    jax >= 0.5 has ``jax.sharding.get_abstract_mesh``; older releases keep
-    the active mesh in the xmap-era thread resources.  Either way the
-    result exposes ``axis_names`` / ``axis_sizes`` and is accepted as
-    ``shard_map``'s mesh argument.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax.interpreters import pxla
-
-    return pxla.thread_resources.env.physical_mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat ``shard_map``: top-level ``jax.shard_map`` with the
-    ``check_vma`` flag on new jax, ``jax.experimental.shard_map.shard_map``
-    with its ``check_rep`` spelling on old jax."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -445,6 +445,9 @@ if __name__ == "__main__":
     )
     args = ap.parse_args()
     if args.demo:
+        from repro.launch.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         demo(args.backend)
     else:
         print("use --demo (library mode: import Server + repro.core.open_index)")

@@ -17,8 +17,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.launch import mesh as mesh_compat
-
 from repro.kernels.flash_attention import flash_attention_pallas, mha_ref
 
 __all__ = ["attention"]
@@ -104,7 +102,7 @@ def flash_decode_sharded(q, k, v, kv_lens, *, model_axis: str, scale: float | No
     group = Hq // Hkv
     if scale is None:
         scale = 1.0 / (d**0.5)
-    mesh = mesh_compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     batch_ax = None
     # infer the batch axis from current mesh axes (pod+data when present)
     bx = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -134,7 +132,7 @@ def flash_decode_sharded(q, k, v, kv_lens, *, model_axis: str, scale: float | No
         acc_g = jax.lax.psum(acc * corr, model_axis)
         return acc_g / jnp.maximum(l_g, 1e-30)
 
-    return mesh_compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

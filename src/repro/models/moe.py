@@ -15,8 +15,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro.launch import mesh as mesh_compat
-
 __all__ = ["MoEConfig", "moe_ffn", "moe_ffn_ep"]
 
 
@@ -81,7 +79,7 @@ def moe_ffn_ep(x, router_w, w_gate, w_up, w_down, cfg: MoEConfig, *, model_axis:
     """
     from jax.sharding import PartitionSpec as _P
 
-    mesh = mesh_compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     n_m = sizes[model_axis]
     E = cfg.n_experts
@@ -118,7 +116,7 @@ def moe_ffn_ep(x, router_w, w_gate, w_up, w_down, cfg: MoEConfig, *, model_axis:
         out = out * gate[:, None].astype(y.dtype)
         return jax.lax.psum(out, model_axis)               # one owner per token
 
-    out = mesh_compat.shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

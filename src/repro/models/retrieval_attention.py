@@ -28,8 +28,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro.launch import mesh as mesh_compat
-
 __all__ = ["ClusteredKVCache", "RetrievalAttnConfig", "init_clustered_cache", "retrieval_decode_attention", "retrieval_decode_attention_sharded", "clustered_cache_update"]
 
 
@@ -154,7 +152,7 @@ def retrieval_update_and_attend_sharded(
     Returns (attn_out [B,Hq,d], layer_k, layer_v, layer_cent) with the
     cache updated at ``pos`` and attention evaluated at ``pos + 1``.
     """
-    mesh = mesh_compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     n_sh = 1
     for a in seq_axes:
@@ -196,7 +194,7 @@ def retrieval_update_and_attend_sharded(
         return out, kb, vb, cb
 
     seq_spec = tuple(seq_axes)
-    return mesh_compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -277,7 +275,7 @@ def retrieval_decode_attention_sharded(
       4. combines with the flash-decoding (m, l, acc) psum — O(B·Hq·d).
     Wire bytes per layer: O(n_sh·b_loc + B·Hq·d) ~ 100 KB vs 8.86 GB.
     """
-    mesh = mesh_compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     n_sh = 1
     for a in seq_axes:
@@ -301,7 +299,7 @@ def retrieval_decode_attention_sharded(
         )
 
     seq_spec = tuple(seq_axes)
-    return mesh_compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

@@ -20,7 +20,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, r"%SRC%")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import set_mesh
 from repro.models import transformer as T
 from repro.models.base import init_params, param_pspecs
 from repro.models.moe import MoEConfig
@@ -43,7 +42,7 @@ params = init_params(specs, jax.random.key(0))
 toks = jax.random.randint(jax.random.key(1), (4, 32), 0, 64)
 ref, _ = T.lm_loss(params, {"tokens": toks}, cfg)
 rules = T.ShardingRules(batch=("data",), model="model", seq="model")
-with set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     pp = put(params, param_pspecs(specs))
     tt = jax.device_put(toks, NamedSharding(mesh, P("data", None)))
     sp, _ = jax.jit(lambda p, b: T.lm_loss(p, b, cfg, rules))(pp, {"tokens": tt})
@@ -62,7 +61,7 @@ cfg = T.LMConfig(name="t", n_layers=2, d_model=32, n_heads=8, n_kv_heads=2,
 specs = T.param_specs(cfg)
 params = init_params(specs, jax.random.key(0))
 ref, _ = T.lm_loss(params, {"tokens": toks}, cfg)
-with set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     pp = put(params, param_pspecs(specs))
     tt = jax.device_put(toks, NamedSharding(mesh, P("data", None)))
     sp, _ = jax.jit(lambda p, b: T.lm_loss(p, b, cfg, rules))(pp, {"tokens": tt})
@@ -77,7 +76,7 @@ vc = jnp.asarray(rng.normal(size=(B, Hkv, nC, cs, d)), jnp.float32)
 cent = jnp.asarray(kc.mean(3), jnp.float32)
 for pos in (5, 37, 128):
     ref = retrieval_decode_attention(q, kc, vc, cent, jnp.asarray(pos), cs=cs, top_b=4)
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         sh = lambda *a: NamedSharding(mesh, P(*a))
         out = jax.jit(lambda q, k, v, c, p: retrieval_decode_attention_sharded(
             q, k, v, c, p, cs=cs, top_b=4, seq_axes=("data", "model")))(
@@ -98,7 +97,7 @@ vn = jnp.asarray(rng.normal(size=(B, Hkv, d)), jnp.float32)
 for pos in (0, 36, 99):
     kc2, vc2, cent2 = clustered_cache_update(kc, vc, cent, kn, vn, jnp.asarray(pos), cs)
     ref = retrieval_decode_attention(q, kc2, vc2, cent2, jnp.asarray(pos + 1), cs=cs, top_b=4)
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         sh = lambda *a: NamedSharding(mesh, P(*a))
         out, ks, vs, cs_o = jax.jit(lambda *a: retrieval_update_and_attend_sharded(
             *a, cs=cs, top_b=4, seq_axes=("data", "model")))(
