@@ -158,7 +158,7 @@ def main() -> None:
     sv = serving.run(fast=args.fast)
     _print_table(
         "Concurrent serving — closed-loop QPS/latency, readonly vs "
-        "mixed-with-writer (scheduler row: avg queue-wait in p99_ms col, "
+        "mixed-with-writer (scheduler row: queue waits' mean/p50/p99, "
         "degraded/misses in inserts/deletes cols)",
         sv,
     )

@@ -79,6 +79,16 @@ class StaleQueryError(RuntimeError):
 
 @dataclass
 class SearchStats:
+    """Per-search counts and times.  A single query's stats cover its own
+    work only: ``io`` counts the reads made on the searching thread, so
+    concurrent searches never count each other's.  Times are seconds of
+    wall clock (``core/tracing.span``), each under the span name given;
+    within one search the ``ecp.*`` spans never overlap, so what
+    ``service_s`` holds beyond their sum is the rest of the request (the
+    snapshot lease, emitting the page, waits for the GIL and locks).
+    Batch searches keep the round engine's times and kernel counters on
+    ``batch_stats``."""
+
     node_loads: int = 0            # disk reads (cache misses served from the store);
                                    # in batch mode a row counts the misses IT demanded
                                    # (solo-equivalent) — actual deduped loads live in
@@ -92,6 +102,20 @@ class SearchStats:
                                    # same round triggered (cross-query fetch dedup)
     kernel_launches: int = 0       # grouped device top-k launches (quantized scan);
                                    # exactly one per traversal round that scanned leaves
+    kernel_groups: int = 0         # (query, leaf) groups handed to those launches
+    kernel_rows: int = 0           # live rows in those groups (padding excluded)
+    h2d_bytes: int = 0             # bytes a launch hands the device after the grouped
+                                   # kernel's padding (queries, codes, params, counts)
+    fp_leaf_scans: int = 0         # leaves the quantized scan routed to the
+                                   # full-precision path (cached), bypassing the kernel
+    queue_wait_s: float = 0.0      # scheduler: submit to a worker taking the request
+    service_s: float = 0.0         # scheduler: the worker's request (serve.search)
+    fetch_s: float = 0.0           # store and node-cache reads (ecp.fetch)
+    pack_s: float = 0.0            # packing a round's codes for the launch (ecp.pack)
+    kernel_s: float = 0.0          # the launch through its numpy results (ecp.kernel)
+    expand_s: float = 0.0          # frontier pops, recording results, scoring fp and
+                                   # internal nodes, frontier pushes (ecp.expand)
+    rerank_s: float = 0.0          # quantized rerank outside its reads (ecp.rerank)
     io: IOStats = field(default_factory=IOStats)  # bytes/files/reads at the store;
                                    # zero per-row in batch mode (coalesced reads have
                                    # no per-row attribution; see batch_stats.io)

@@ -41,7 +41,7 @@ import json
 import os
 import threading
 from concurrent.futures import Future
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from pathlib import Path
 
 import numpy as np
@@ -261,14 +261,11 @@ def _sum_stats(per: list[SearchStats]) -> SearchStats:
     for s in per:
         if s is None:
             continue
-        tot.node_loads += s.node_loads
-        tot.nodes_opened += s.nodes_opened
-        tot.leaves_opened += s.leaves_opened
-        tot.distance_calcs += s.distance_calcs
-        tot.increments += s.increments
-        tot.rounds += s.rounds
-        tot.dedup_hits += s.dedup_hits
-        tot.io.add(s.io)
+        for f in fields(SearchStats):
+            if f.name == "io":
+                tot.io.add(s.io)
+            else:
+                setattr(tot, f.name, getattr(tot, f.name) + getattr(s, f.name))
     return tot
 
 

@@ -83,7 +83,8 @@ from .api import NodeCache, Query, ResultSet, SearchStats, StaleQueryError, pack
 from .distances import np_distances
 from .frontier import CandidateBuffer, Frontier
 from .quant import QFORMATS, distance_bounds, encode_node, qdtype
-from .store import NodeNormCache, Store, open_store
+from .store import NodeNormCache, Store, io_sink, open_store
+from .tracing import span
 
 __all__ = [
     "ECPIndex",
@@ -104,6 +105,13 @@ ENGINES = ("flat", "legacy")
 # per-query cap on the exact-distance watermark array the quantized scan
 # keeps for cross-leaf pruning (QueryState.best_d)
 BEST_D_CAP = 4096
+
+# what the round engine counts and times on its ``agg``; a single query's
+# quantized increment moves them onto the query's own stats
+ROUND_FIELDS = (
+    "kernel_launches", "kernel_groups", "kernel_rows", "h2d_bytes", "fp_leaf_scans",
+    "fetch_s", "pack_s", "kernel_s", "expand_s", "rerank_s",
+)
 
 
 def _kernel_ops():
@@ -981,18 +989,22 @@ class ECPIndex:
         if self._quantized:
             # the quantized scan lives in the round engine (it is what
             # builds the per-round grouped kernel launch) — a single query
-            # is a batch of one, with io/launches re-attributed to the row
-            io_before = self.store.io.snapshot()
+            # is a batch of one, with io, launches and span times
+            # re-attributed to the row
             agg = SearchStats()
             self._batch_increment([qs], k, agg)
-            qs.stats.kernel_launches += agg.kernel_launches
-            qs.stats.io.add(self.store.io.delta(io_before))
+            for f in ROUND_FIELDS:
+                setattr(qs.stats, f, getattr(qs.stats, f) + getattr(agg, f))
+            qs.stats.io.add(agg.io)
             return
+        with io_sink(qs.stats.io):  # this thread's reads are this query's
+            self._fp_increment(qs, k)
+
+    def _fp_increment(self, qs: QueryState, k: int) -> None:
         info = self.info
         leaf_cnt = 0
         qs.b_cur = qs.b  # each increment starts from the configured budget
         loads_before = self.load_node_count
-        io_before = self.store.io.snapshot()
         qs._excl_arr = None  # re-read the (mutable) exclude set
 
         if not qs.started:
@@ -1039,10 +1051,8 @@ class ECPIndex:
             if stop:
                 break
         qs.stats.node_loads += self.load_node_count - loads_before
-        # NOTE: with an AsyncPrefetchStore, background reads count when they
-        # complete, so per-traversal io can lag slightly; store.drain() gives
-        # exact attribution (benchmarks use it between passes)
-        qs.stats.io.add(self.store.io.delta(io_before))
+        # NOTE: with an AsyncPrefetchStore, background reads run on the
+        # prefetch pool's threads and count on the store's io only
         qs.I.commit()
 
     # --------------------------------------------- Algorithm 3, batch mode
@@ -1061,13 +1071,17 @@ class ECPIndex:
         the misses *it* demanded (what a solo run would have read) with
         ``dedup_hits`` for demands served by another row's load in the
         same round.  ``agg`` gets the actual deduped loads, total rounds,
-        total dedup savings, and the store io delta of the whole call
+        total dedup savings, the reads this thread made in the whole call
         (per-row ``stats.io`` stays zero in batch mode — coalesced reads
-        have no per-row attribution).
+        have no per-row attribution), the kernel counters and the times
+        of the ``ecp.*`` spans.
         """
+        with io_sink(agg.io):
+            self._rounds(states, k, agg)
+
+    def _rounds(self, states: list, k: int, agg: SearchStats) -> None:
         info = self.info
         quant = self._quantized
-        io_before = self.store.io.snapshot()
         for qs in states:
             qs._excl_arr = None  # re-read the (mutable) exclude set
             qs.b_cur = qs.b  # each increment starts from the configured budget
@@ -1078,129 +1092,145 @@ class ECPIndex:
         leaf_cnt = {id(qs): 0 for qs in states}
         pending: list = []  # quantized (query, leaf) units awaiting rerank
         active = [qs for qs in states if qs.T]
-        while active:
-            agg.rounds += 1
-            pops = []
-            for qs in active:
-                # multi-probe: each round takes the row's top-probe_m
-                # frontier entries (probe_m=1 = the old single pop), so
-                # the round's dedup/coalescing window widens with m
-                for _ in range(min(qs.probe_m, len(qs.T))):
-                    d0, is_leaf, level, node = qs.T.pop()
-                    qs.stats.nodes_opened += 1
-                    pops.append((qs, is_leaf, level, node))
-                qs.stats.rounds += 1
-            # cross-query fetch dedup: unique (level, node) demands, one
-            # batched read for all of them
-            key_rows: dict[tuple, list] = {}
-            for p in pops:
-                key_rows.setdefault((p[2], p[3]), []).append(p)
-            keys = list(key_rows)
-            # quantized mode scans leaves from the compressed companion
-            # blocks; only internal nodes go through the fp payload path.
-            # A leaf whose full fp node is already cached (a prior rerank
-            # fetched it), or whose row cache was born in an earlier call
-            # (repeat demand — read it whole once, scan it cheap forever),
-            # skips the kernel + rerank entirely and scans through the fp
-            # path — the results are bit-identical either way, and the
-            # warm path costs what the plain engine's does.
-            if quant:
-                leaf_keys = [
-                    key
-                    for key in keys
-                    if key_rows[key][0][1] and not self._fp_leaf(key)
-                ]
-                lset = set(leaf_keys)
-                fp_keys = [key for key in keys if key not in lset]
-            else:
-                leaf_keys, fp_keys = [], keys
-            missing = {
-                key for key in fp_keys if not self.cache.contains(self._key(*key))
-            }
-            missing |= {
-                key
-                for key in leaf_keys
-                if not self.cache.contains(self._key(*key) + ("q",))
-            }
-            payloads = dict(zip(fp_keys, self.get_nodes(fp_keys))) if fp_keys else {}
-            qpayloads = (
-                dict(zip(leaf_keys, self._get_quant_nodes(leaf_keys)))
-                if leaf_keys
-                else {}
-            )
-            for key in keys:
-                demanders = key_rows[key]
-                if key in missing:
-                    agg.node_loads += 1
-                    agg.dedup_hits += len(demanders) - 1
-                    for j, p in enumerate(demanders):
-                        p[0].stats.node_loads += 1
-                        if j:
-                            p[0].stats.dedup_hits += 1
-            hints: dict[tuple, None] = {}
-            done: set[int] = set()
-            if leaf_keys:
-                self._quant_scan_round(
-                    leaf_keys, key_rows, qpayloads, k, agg, leaf_cnt, done, pending
+        with span("ecp.expand", agg, "expand_s"):
+            demand = self._demands(active, agg) if active else None
+        while demand is not None:
+            key_rows, keys, leaf_keys, fp_keys, missing = demand
+            with span("ecp.fetch", agg, "fetch_s"):
+                payloads = dict(zip(fp_keys, self.get_nodes(fp_keys))) if fp_keys else {}
+                qpayloads = (
+                    dict(zip(leaf_keys, self._get_quant_nodes(leaf_keys)))
+                    if leaf_keys
+                    else {}
                 )
-            for key in fp_keys:
-                emb, ids = payloads[key]
-                if len(ids) == 0:
-                    continue
-                level, node = key
-                demanders = key_rows[key]
-                is_leaf = bool(demanders[0][1])
-                sq = self._sqnorms(level, node, emb)
-                D = None
-                if self._batch_matrix and len(demanders) >= 4 and not (is_leaf and (self._scorer is not None or quant)):
-                    # opt-in dense [B', N] block (not bit-exact across B');
-                    # only pays off once enough rows co-demand the node
-                    D = np_distances(
-                        np.stack([p[0].q for p in demanders]), emb, info.metric, c_sqnorms=sq
-                    )
-                for r, (qs, _, _, _) in enumerate(demanders):
-                    d = D[r] if D is not None else self._score_row(
-                        qs.q, emb, sq, leaf=is_leaf and not quant
-                    )
-                    qs.stats.distance_calcs += len(ids)
-                    if is_leaf:
-                        qs.stats.leaves_opened += 1
-                        d_f, _ = self._stage_leaf(qs, d, ids)
-                        if qs.virtual_i is not None:
-                            # a fully-staged leaf advances the virtual
-                            # count by its live rows, and its exact
-                            # distances tighten the cross-leaf watermark
-                            qs.virtual_i += int(len(d_f))
-                            self._note_exact(qs, d_f)
-                        leaf_cnt[id(qs)] += 1
-                        if leaf_cnt[id(qs)] >= qs.b_cur:
-                            if self._ilen(qs) >= k:
-                                done.add(id(qs))
-                            elif qs.mx_inc == -1 or qs.increments < qs.mx_inc:
-                                qs.increments += 1
-                                qs.stats.increments += 1
-                                qs.b_cur *= 2
-                            else:
-                                done.add(id(qs))
-                    else:
-                        qs.T.push_batch(d, ids, 1 if (level + 1) == info.levels else 0, level + 1)
-                        if self._store_prefetch is not None:
-                            for hk in self._prefetch_hint(level + 1, ids, d):
-                                hints[hk] = None
-            if hints:
-                self._store_prefetch(list(hints), on_node=self._on_prefetched)
-            active = [qs for qs in active if id(qs) not in done and qs.T]
-        self._quant_finalize(pending)
-        agg.io.add(self.store.io.delta(io_before))
+            launched = (
+                self._quant_launch(leaf_keys, key_rows, qpayloads, k, agg) if leaf_keys else None
+            )
+            with span("ecp.expand", agg, "expand_s"):
+                hints: dict[tuple, None] = {}
+                done: set[int] = set()
+                if launched is not None:
+                    self._quant_record(*launched, k, leaf_cnt, done, pending)
+                for key in keys:
+                    demanders = key_rows[key]
+                    if key in missing:
+                        agg.node_loads += 1
+                        agg.dedup_hits += len(demanders) - 1
+                        for j, p in enumerate(demanders):
+                            p[0].stats.node_loads += 1
+                            if j:
+                                p[0].stats.dedup_hits += 1
+                for key in fp_keys:
+                    emb, ids = payloads[key]
+                    if len(ids) == 0:
+                        continue
+                    level, node = key
+                    demanders = key_rows[key]
+                    is_leaf = bool(demanders[0][1])
+                    sq = self._sqnorms(level, node, emb)
+                    D = None
+                    if self._batch_matrix and len(demanders) >= 4 and not (is_leaf and (self._scorer is not None or quant)):
+                        # opt-in dense [B', N] block (not bit-exact across B');
+                        # only pays off once enough rows co-demand the node
+                        D = np_distances(
+                            np.stack([p[0].q for p in demanders]), emb, info.metric, c_sqnorms=sq
+                        )
+                    for r, (qs, _, _, _) in enumerate(demanders):
+                        d = D[r] if D is not None else self._score_row(
+                            qs.q, emb, sq, leaf=is_leaf and not quant
+                        )
+                        qs.stats.distance_calcs += len(ids)
+                        if is_leaf:
+                            qs.stats.leaves_opened += 1
+                            if quant:
+                                agg.fp_leaf_scans += 1
+                            d_f, _ = self._stage_leaf(qs, d, ids)
+                            if qs.virtual_i is not None:
+                                # a fully-staged leaf advances the virtual
+                                # count by its live rows, and its exact
+                                # distances tighten the cross-leaf watermark
+                                qs.virtual_i += int(len(d_f))
+                                self._note_exact(qs, d_f)
+                            leaf_cnt[id(qs)] += 1
+                            if leaf_cnt[id(qs)] >= qs.b_cur:
+                                if self._ilen(qs) >= k:
+                                    done.add(id(qs))
+                                elif qs.mx_inc == -1 or qs.increments < qs.mx_inc:
+                                    qs.increments += 1
+                                    qs.stats.increments += 1
+                                    qs.b_cur *= 2
+                                else:
+                                    done.add(id(qs))
+                        else:
+                            qs.T.push_batch(d, ids, 1 if (level + 1) == info.levels else 0, level + 1)
+                            if self._store_prefetch is not None:
+                                for hk in self._prefetch_hint(level + 1, ids, d):
+                                    hints[hk] = None
+                if hints:
+                    self._store_prefetch(list(hints), on_node=self._on_prefetched)
+                active = [qs for qs in active if id(qs) not in done and qs.T]
+                demand = self._demands(active, agg) if active else None
+        self._quant_finalize(pending, agg)
         for qs in states:
             qs.I.commit()
 
+    def _demands(self, active: list, agg: SearchStats):
+        """One round's node demands: pop each active row's next frontier
+        entries, dedupe them by node, and route each leaf to the kernel
+        or the full-precision path.  Returns the demanders of each node,
+        the nodes, the kernel's leaves, the fp nodes, and which of them
+        miss the cache."""
+        quant = self._quantized
+        agg.rounds += 1
+        pops = []
+        for qs in active:
+            # multi-probe: each round takes the row's top-probe_m
+            # frontier entries (probe_m=1 = the old single pop), so
+            # the round's dedup/coalescing window widens with m
+            for _ in range(min(qs.probe_m, len(qs.T))):
+                d0, is_leaf, level, node = qs.T.pop()
+                qs.stats.nodes_opened += 1
+                pops.append((qs, is_leaf, level, node))
+            qs.stats.rounds += 1
+        # cross-query fetch dedup: unique (level, node) demands, one
+        # batched read for all of them
+        key_rows: dict[tuple, list] = {}
+        for p in pops:
+            key_rows.setdefault((p[2], p[3]), []).append(p)
+        keys = list(key_rows)
+        # quantized mode scans leaves from the compressed companion
+        # blocks; only internal nodes go through the fp payload path.
+        # A leaf whose full fp node is already cached (a prior rerank
+        # fetched it), or whose row cache was born in an earlier call
+        # (repeat demand — read it whole once, scan it cheap forever),
+        # skips the kernel + rerank entirely and scans through the fp
+        # path — the results are bit-identical either way, and the
+        # warm path costs what the plain engine's does.
+        if quant:
+            leaf_keys = [
+                key
+                for key in keys
+                if key_rows[key][0][1] and not self._fp_leaf(key)
+            ]
+            lset = set(leaf_keys)
+            fp_keys = [key for key in keys if key not in lset]
+        else:
+            leaf_keys, fp_keys = [], keys
+        missing = {
+            key for key in fp_keys if not self.cache.contains(self._key(*key))
+        }
+        missing |= {
+            key
+            for key in leaf_keys
+            if not self.cache.contains(self._key(*key) + ("q",))
+        }
+        return key_rows, keys, leaf_keys, fp_keys, missing
+
     # ------------------------------------------- quantized leaf scan round
-    def _quant_scan_round(
-        self, leaf_keys, key_rows, qpayloads, k, agg, leaf_cnt, done, pending
-    ) -> None:
+    def _quant_launch(self, leaf_keys, key_rows, qpayloads, k, agg):
         """Scan every (query, leaf) unit of one traversal round from the
         quantized companion blocks with ONE grouped device launch.
+        Returns what ``_quant_record`` takes, or None when no leaf has rows.
 
         Only the approximate results are produced here — they go on
         ``pending`` and are reranked once, at the end of the increment
@@ -1211,44 +1241,61 @@ class ECPIndex:
         levels), so deferring the rerank cannot change which nodes are
         visited."""
         info = self.info
-        metric = info.metric
+        ops = _kernel_ops()
+        with span("ecp.pack", agg, "pack_s"):
+            units = []  # (qs, key, qn, R)
+            for key in leaf_keys:
+                qn = qpayloads[key]
+                if qn.n_rows == 0:
+                    continue  # matches the fp engines: empty nodes cost nothing
+                for qs, _leaf, _lv, _nd in key_rows[key]:
+                    units.append(
+                        (qs, key, qn, max(self._rerank_depth or 0, qs.emitted + k))
+                    )
+            if not units:
+                return None
+            G = len(units)
+            n_max = max(u[2].n_rows for u in units)
+            r_max = max(u[3] for u in units)
+            kop = min(n_max, -(-(r_max + 16) // 32) * 32)
+            q_arr = np.stack([np.asarray(u[0].q, np.float32) for u in units])
+            codes = np.zeros((G, n_max, info.dim), qdtype(self._qformat))
+            scales = np.zeros(G, np.float32)
+            offsets = np.zeros(G, np.float32)
+            n_rows = np.zeros(G, np.int32)
+            for g, (qs, key, qn, R) in enumerate(units):
+                codes[g, : qn.n_rows] = qn.codes
+                scales[g] = qn.scale
+                offsets[g] = qn.offset
+                n_rows[g] = qn.n_rows
+        # ids of the leaves whose dead rows (tombstones, excluded ids) the
+        # recording filters
         tomb = self._tomb_sorted()
-        units = []  # (qs, key, qn, R)
-        for key in leaf_keys:
-            qn = qpayloads[key]
-            if qn.n_rows == 0:
-                continue  # matches the fp engines: empty nodes cost nothing
-            for qs, _leaf, _lv, _nd in key_rows[key]:
-                units.append(
-                    (qs, key, qn, max(self._rerank_depth or 0, qs.emitted + k))
-                )
-        if not units:
-            return
+        leaf_ids = [None] * G
+        if tomb is not None or any(u[0].exclude for u in units):
+            with span("ecp.fetch", agg, "fetch_s"):
+                for g, (qs, key, _, _) in enumerate(units):
+                    if tomb is not None or qs.exclude:
+                        leaf_ids[g] = self._get_leaf_ids(*key)
         # ---- the round's single grouped kernel launch
-        G = len(units)
-        n_max = max(u[2].n_rows for u in units)
-        r_max = max(u[3] for u in units)
-        kop = min(n_max, -(-(r_max + 16) // 32) * 32)
-        q_arr = np.stack([np.asarray(u[0].q, np.float32) for u in units])
-        codes = np.zeros((G, n_max, info.dim), qdtype(self._qformat))
-        scales = np.zeros(G, np.float32)
-        offsets = np.zeros(G, np.float32)
-        n_rows = np.zeros(G, np.int32)
-        for g, (qs, key, qn, R) in enumerate(units):
-            codes[g, : qn.n_rows] = qn.codes
-            scales[g] = qn.scale
-            offsets[g] = qn.offset
-            n_rows[g] = qn.n_rows
-        dists, idxs = _kernel_ops().grouped_distance_topk(
-            q_arr, codes, scales, offsets, n_rows, kop, metric, self._qformat
-        )
+        with span("ecp.kernel", agg, "kernel_s"):
+            dists, idxs = ops.grouped_distance_topk(
+                q_arr, codes, scales, offsets, n_rows, kop, info.metric, self._qformat
+            )
         agg.kernel_launches += 1
-        # ---- record approximate results; advance per-query control flow
+        agg.kernel_groups += G
+        agg.kernel_rows += int(n_rows.sum())
+        agg.h2d_bytes += ops.grouped_launch_bytes(G, n_max, info.dim, codes.dtype.itemsize)
+        return units, dists, idxs, kop, leaf_ids, tomb
+
+    def _quant_record(self, units, dists, idxs, kop, leaf_ids, tomb, k, leaf_cnt, done, pending) -> None:
+        """Record one launch's approximate results on ``pending`` and
+        advance each unit's query control flow."""
         for g, (qs, key, qn, R) in enumerate(units):
             dead_rows = None
             n_dead = 0
             if tomb is not None or qs.exclude:
-                ids = self._get_leaf_ids(*key)
+                ids = leaf_ids[g]
                 dead = np.zeros(len(ids), bool)
                 if tomb is not None:
                     dead |= np.isin(ids, tomb)
@@ -1285,7 +1332,7 @@ class ECPIndex:
                 else:
                     done.add(id(qs))
 
-    def _quant_finalize(self, pending) -> None:
+    def _quant_finalize(self, pending, agg: SearchStats) -> None:
         """End-of-increment rerank of every pending (query, leaf) unit.
 
         Pass 1 live-filters each unit and pools its exact-distance upper
@@ -1307,9 +1354,30 @@ class ECPIndex:
         full leaf shape so every scoring GEMM below has exactly the shape
         the fp engine's has, and a GEMM's per-column results depend only
         on that column's data — so staged distances stay bit-identical (a
-        subset-shaped GEMM would drift in the last ulp)."""
+        subset-shaped GEMM would drift in the last ulp).
+
+        Its reads are ``ecp.fetch``; the rest is ``ecp.rerank``."""
         if not pending:
             return
+        with span("ecp.rerank", agg, "rerank_s"):
+            selections, unions, full_keys, plans = self._quant_plan(pending)
+        # ---- survivor fetch: one coalescing full read + row-cache top-ups
+        with span("ecp.fetch", agg, "fetch_s"):
+            full_payloads = (
+                dict(zip(full_keys, self.get_nodes(full_keys))) if full_keys else {}
+            )
+            rows_read = {
+                key: self.store.get_node_rows(*key, need)
+                for key, (_, _, need) in plans.items()
+                if len(need)
+            }
+        with span("ecp.rerank", agg, "rerank_s"):
+            self._quant_rescore(selections, unions, full_payloads, plans, rows_read)
+
+    def _quant_plan(self, pending):
+        """Passes 1 and 2 of ``_quant_finalize``, then what to read:
+        returns each unit's survivors, each leaf's union of them, the
+        leaves to read whole, and the row-cache top-ups of the rest."""
         info = self.info
         metric = info.metric
         # ---- pass 1: live-filter, bounds, per-query upper-bound pool
@@ -1375,7 +1443,6 @@ class ECPIndex:
             selections.append((qs, key, qn, rows))
             if len(rows):
                 need_rows.setdefault(key, []).append(rows)
-        # ---- survivor fetch: one coalescing full read + row-cache top-ups
         partial_getter = getattr(self.store, "get_node_rows", None)
         unions: dict[tuple, np.ndarray] = {}
         full_keys: list = []
@@ -1401,9 +1468,12 @@ class ECPIndex:
                 full_keys.append(key)
             else:
                 plans[key] = (rkey, rc, missing)
-        full_payloads = (
-            dict(zip(full_keys, self.get_nodes(full_keys))) if full_keys else {}
-        )
+        return selections, unions, full_keys, plans
+
+    def _quant_rescore(self, selections, unions, full_payloads, plans, rows_read) -> None:
+        """Fold the survivor reads into the row caches, then score and
+        stage every unit's survivors exactly."""
+        n_of = {key: qn.n_rows for _, key, qn, _ in selections}
         fetched: dict[tuple, tuple] = {}
         for key, union in unions.items():
             if key in full_payloads:
@@ -1412,9 +1482,9 @@ class ECPIndex:
             else:
                 rkey, rc, need = plans[key]
                 if rc is None:
-                    rc = _LeafRowCache(n_of[key], info.dim, self._quant_seq)
+                    rc = _LeafRowCache(n_of[key], self.info.dim, self._quant_seq)
                 if len(need):
-                    emb_rows, ids_rows = partial_getter(*key, need)
+                    emb_rows, ids_rows = rows_read[key]
                     rc.emb[need] = emb_rows
                     rc.ids[need] = ids_rows
                     rc.have[need] = True
@@ -1431,7 +1501,7 @@ class ECPIndex:
             if not len(rows):
                 continue
             emb, sq, ids = fetched[key]
-            d_full = np_distances(qs.q, emb, metric, c_sqnorms=sq)
+            d_full = np_distances(qs.q, emb, self.info.metric, c_sqnorms=sq)
             d_live, _ = self._stage_leaf(qs, d_full[rows], ids[rows])
             self._note_exact(qs, d_live)
 

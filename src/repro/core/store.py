@@ -101,6 +101,7 @@ from .quant import QFORMATS, QuantNode, encode_node, qdtype
 
 __all__ = [
     "IOStats",
+    "io_sink",
     "Store",
     "FStoreBackend",
     "BlobStore",
@@ -118,8 +119,15 @@ BLOB_FILENAME = "index.blob"
 
 
 # ------------------------------------------------------------------- IOStats
+_SINK = threading.local()  # .io: the IOStats of the search running on this thread
+
+
 class IOStats:
     """Thread-safe I/O counters: bytes read, files opened, reads issued.
+
+    Every count also goes to the searching thread's sink, if one is set
+    (``io_sink``): that is how a search's ``SearchStats.io`` counts its
+    own reads and none of a concurrent search's on the same store.
 
     Prefetch accuracy rides along: ``prefetch_issued`` counts background
     reads scheduled, ``prefetch_hits`` counts prefetched payloads a demand
@@ -165,6 +173,9 @@ class IOStats:
             self.bytes_read += int(nbytes)
             self.files_opened += files
             self.reads_issued += reads
+        sink = getattr(_SINK, "io", None)
+        if sink is not None and sink is not self:
+            sink.count(nbytes, files=files, reads=reads)
 
     def count_internal(self, reads: int = 1) -> None:
         """Internal-level (non-leaf) node loads that missed the cache —
@@ -173,12 +184,18 @@ class IOStats:
         ~0 on warm queries; the counter is the proof."""
         with self._lock:
             self.internal_reads += reads
+        sink = getattr(_SINK, "io", None)
+        if sink is not None and sink is not self:
+            sink.count_internal(reads)
 
     def count_prefetch(self, *, issued: int = 0, hits: int = 0, wasted_bytes: int = 0) -> None:
         with self._lock:
             self.prefetch_issued += issued
             self.prefetch_hits += hits
             self.prefetch_wasted_bytes += int(wasted_bytes)
+        sink = getattr(_SINK, "io", None)
+        if sink is not None and sink is not self:
+            sink.count_prefetch(issued=issued, hits=hits, wasted_bytes=wasted_bytes)
 
     def snapshot(self) -> "IOStats":
         with self._lock:
@@ -234,6 +251,25 @@ class IOStats:
             f"prefetch_hits={self.prefetch_hits}, "
             f"prefetch_wasted_bytes={self.prefetch_wasted_bytes})"
         )
+
+
+class io_sink:
+    """``with io_sink(io):`` — every ``IOStats`` count this thread makes
+    inside the block is also added to ``io``.  Blocks nest: the innermost
+    sink takes the counts."""
+
+    __slots__ = ("io", "_prev")
+
+    def __init__(self, io: IOStats):
+        self.io = io
+
+    def __enter__(self) -> IOStats:
+        self._prev = getattr(_SINK, "io", None)
+        _SINK.io = self.io
+        return self.io
+
+    def __exit__(self, *exc) -> None:
+        _SINK.io = self._prev
 
 
 # ------------------------------------------------------------------ protocol

@@ -37,6 +37,21 @@ from jax.experimental.pallas import tpu as pltpu
 from .distance_topk import _merge_topk
 
 NEG_ONE = -1
+BN = 128  # candidate rows per grid step
+
+
+def padded_shape(G: int, N: int, bn: int = BN) -> tuple[int, int]:
+    """The (groups, rows) a launch of G groups of at most N rows is padded
+    to: G to a power of two, N to a multiple of ``bn``."""
+    return 1 << max(G - 1, 0).bit_length(), -(-max(N, 1) // bn) * bn
+
+
+def launch_bytes(G: int, N: int, D: int, code_itemsize: int, bn: int = BN) -> int:
+    """Bytes a launch hands the device after padding: codes
+    ``[G_pad, N_pad, D]``, queries ``[G_pad, 1, D]`` f32, dequant params
+    ``[G_pad, 1, 2]`` f32 and row counts ``[G_pad, 1, 1]`` i32."""
+    G_pad, N_pad = padded_shape(G, N, bn)
+    return G_pad * (N_pad * D * code_itemsize + D * 4 + 2 * 4 + 4)
 
 
 def _gkernel(
@@ -145,7 +160,7 @@ def grouped_distance_topk_pallas(
     metric: str = "l2",
     qformat: str = "int8",
     *,
-    bn: int = 128,
+    bn: int = BN,
     interpret: bool = False,
 ):
     """q [G, D], codes [G, N, D] (int8|f16), scales/offsets [G],
@@ -154,8 +169,7 @@ def grouped_distance_topk_pallas(
     q = np.asarray(q, np.float32)
     codes = np.asarray(codes)
     G, N, D = codes.shape
-    G_pad = 1 << max(G - 1, 0).bit_length()
-    N_pad = -(-max(N, 1) // bn) * bn
+    G_pad, N_pad = padded_shape(G, N, bn)
     if (G_pad, N_pad) != (G, N):
         padded = np.zeros((G_pad, N_pad, D), codes.dtype)
         padded[:G, :N] = codes

@@ -11,6 +11,7 @@ import jax
 
 from .distance_topk import distance_topk_pallas
 from .grouped import grouped_distance_topk_pallas
+from .grouped import launch_bytes as grouped_launch_bytes  # noqa: F401  (a launch's bytes)
 from .ref import distance_topk_ref, grouped_distance_topk_ref
 
 

@@ -46,6 +46,9 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core.api import SearchStats
+from repro.core.tracing import span
+
 __all__ = [
     "DeadlinePolicy",
     "RequestScheduler",
@@ -124,7 +127,6 @@ class SnapshotManager:
         self._index = index
         self._lock = threading.Lock()
         self._cur = None
-        self.refreshes = 0
 
     def lease(self):
         with self._lock:
@@ -150,7 +152,6 @@ class SnapshotManager:
     def _repin_locked(self) -> None:
         new = self._index.snapshot()
         old, self._cur = self._cur, new
-        self.refreshes += 1
         if old is not None:
             old.release()
 
@@ -391,22 +392,28 @@ class RequestScheduler:
         b_eff = b_req
         if req.deadline is not None:
             b_eff = self.policy.choose_b(b_req, req.deadline - t0)
-        lease = None
-        if self.snapshots is not None:
-            lease = self.snapshots.lease()
-            searcher = lease
-        else:
-            self._rw.acquire_read()
-            searcher = self.index
-        try:
-            rs = searcher.search(np.asarray(req.q, np.float32), req.k, b=b_eff, **req.opts)
-        except BaseException:
-            if lease is not None:
-                lease.release()
-            raise
-        finally:
-            if lease is None:
-                self._rw.release_read()
+        with span("serve.search") as served:
+            lease = None
+            if self.snapshots is not None:
+                lease = self.snapshots.lease()
+                searcher = lease
+            else:
+                self._rw.acquire_read()
+                searcher = self.index
+            try:
+                rs = searcher.search(np.asarray(req.q, np.float32), req.k, b=b_eff, **req.opts)
+            except BaseException:
+                if lease is not None:
+                    lease.release()
+                raise
+            finally:
+                if lease is None:
+                    self._rw.release_read()
+        stats = getattr(rs, "stats", None)
+        for st in stats if isinstance(stats, list) else [stats]:
+            if isinstance(st, SearchStats):
+                st.queue_wait_s = t0 - req.t_submit
+                st.service_s = served.seconds
         done = time.monotonic()
         self.policy.observe(b_eff, done - t0)
         with self.stats.lock:

@@ -50,6 +50,7 @@ from repro.core import (
     convert,
     open_index,
 )
+from repro.core.tracing import span
 from repro.data import clustered_vectors
 from repro.launch.scheduler import (
     DeadlinePolicy,
@@ -295,7 +296,7 @@ class Server:
             if self.scheduler is not None and sess.lease is None
             else _NULL_CTX
         )
-        with sess.lock, guard:
+        with sess.lock, guard, span("serve.more"):
             rs = sess.query.next(k)
         self.stats.count(
             "continuations", 1 if rs.ids.ndim == 1 else rs.ids.shape[0]
