@@ -100,8 +100,9 @@ class SearchStats:
     rounds: int = 0                # lockstep batch rounds participated in (batch mode)
     dedup_hits: int = 0            # node demands served by a load another query in the
                                    # same round triggered (cross-query fetch dedup)
-    kernel_launches: int = 0       # grouped device top-k launches (quantized scan);
-                                   # exactly one per traversal round that scanned leaves
+    kernel_launches: int = 0       # grouped device top-k launches (quantized scan):
+                                   # a few per increment, at most one per traversal
+                                   # round that scanned leaves
     kernel_groups: int = 0         # (query, leaf) groups handed to those launches
     kernel_rows: int = 0           # live rows in those groups (padding excluded)
     h2d_bytes: int = 0             # bytes a launch hands the device after the grouped
@@ -111,7 +112,7 @@ class SearchStats:
     queue_wait_s: float = 0.0      # scheduler: submit to a worker taking the request
     service_s: float = 0.0         # scheduler: the worker's request (serve.search)
     fetch_s: float = 0.0           # store and node-cache reads (ecp.fetch)
-    pack_s: float = 0.0            # packing a round's codes for the launch (ecp.pack)
+    pack_s: float = 0.0            # packing a launch's codes (ecp.pack)
     kernel_s: float = 0.0          # the launch through its numpy results (ecp.kernel)
     expand_s: float = 0.0          # frontier pops, recording results, scoring fp and
                                    # internal nodes, frontier pushes (ecp.expand)

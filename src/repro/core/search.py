@@ -41,10 +41,13 @@ interpreter overhead, not file I/O):
 
 ``ECPIndex(quantized=True)`` turns on the device-resident scoring
 pipeline: leaf scans read the blob's scalar-quantized companion blocks
-(core/quant.py, blob format v3 — an fstore or v2 blob encodes on the fly)
-and every traversal round launches ONE grouped ``distance_topk`` kernel
-over all (query, leaf) scan units of the round
-(kernels/distance_topk/grouped.py).  Survivor selection keeps every row
+(core/quant.py, blob format v3 — an fstore or v2 blob encodes on the fly).
+Each round records its (query, leaf) scan units and advances the
+traversal; at the end of the increment the units go to the grouped
+``distance_topk`` kernel (kernels/distance_topk/grouped.py) sorted by
+rows, in a few launches of up to 128 groups (about 8 for a query at
+b=64), never more launches than the increment had leaf-bearing rounds.
+Survivor selection keeps every row
 whose sound distance lower bound could still reach the query's rerank
 depth ``R = max(rerank_depth, emitted + k)``; survivors are re-scored
 against the full-precision rows (partial row reads where the store
@@ -112,6 +115,11 @@ ROUND_FIELDS = (
     "kernel_launches", "kernel_groups", "kernel_rows", "h2d_bytes", "fp_leaf_scans",
     "fetch_s", "pack_s", "kernel_s", "expand_s", "rerank_s",
 )
+
+
+def _pow2(n: int) -> int:
+    """The least power of two at or above ``n`` (1 for ``n <= 1``)."""
+    return 1 << max(n - 1, 0).bit_length()
 
 
 def _kernel_ops():
@@ -988,9 +996,9 @@ class ECPIndex:
     def _increment(self, qs: QueryState, k: int) -> None:
         if self._quantized:
             # the quantized scan lives in the round engine (it is what
-            # builds the per-round grouped kernel launch) — a single query
-            # is a batch of one, with io, launches and span times
-            # re-attributed to the row
+            # defers the leaf scans to the increment's grouped launches) —
+            # a single query is a batch of one, with io, launches and span
+            # times re-attributed to the row
             agg = SearchStats()
             self._batch_increment([qs], k, agg)
             for f in ROUND_FIELDS:
@@ -1080,6 +1088,10 @@ class ECPIndex:
             self._rounds(states, k, agg)
 
     def _rounds(self, states: list, k: int, agg: SearchStats) -> None:
+        """The rounds of one increment.  A quantized leaf scan only records
+        its unit and advances the walk (``_quant_record``); the units run
+        at the end of the increment as a few grouped launches sorted by
+        rows (``_quant_scan``), then are reranked (``_quant_finalize``)."""
         info = self.info
         quant = self._quantized
         for qs in states:
@@ -1090,7 +1102,8 @@ class ECPIndex:
             if quant and qs.virtual_i is None:
                 qs.virtual_i = len(qs.I)
         leaf_cnt = {id(qs): 0 for qs in states}
-        pending: list = []  # quantized (query, leaf) units awaiting rerank
+        deferred: list = []  # quantized (query, leaf) scan units, in round order
+        scan_rounds = 0  # rounds that gave the scan a unit
         active = [qs for qs in states if qs.T]
         with span("ecp.expand", agg, "expand_s"):
             demand = self._demands(active, agg) if active else None
@@ -1103,14 +1116,22 @@ class ECPIndex:
                     if leaf_keys
                     else {}
                 )
-            launched = (
-                self._quant_launch(leaf_keys, key_rows, qpayloads, k, agg) if leaf_keys else None
-            )
+                # ids of the leaves whose dead rows (tombstones, excluded
+                # ids) the recording filters
+                tomb = self._tomb_sorted() if leaf_keys else None
+                leaf_ids = {
+                    key: self._get_leaf_ids(*key)
+                    for key in leaf_keys
+                    if qpayloads[key].n_rows
+                    and (tomb is not None or any(p[0].exclude for p in key_rows[key]))
+                }
             with span("ecp.expand", agg, "expand_s"):
                 hints: dict[tuple, None] = {}
                 done: set[int] = set()
-                if launched is not None:
-                    self._quant_record(*launched, k, leaf_cnt, done, pending)
+                if leaf_keys:
+                    scan_rounds += self._quant_record(
+                        leaf_keys, key_rows, qpayloads, leaf_ids, tomb, k, leaf_cnt, done, deferred
+                    )
                 for key in keys:
                     demanders = key_rows[key]
                     if key in missing:
@@ -1170,7 +1191,7 @@ class ECPIndex:
                     self._store_prefetch(list(hints), on_node=self._on_prefetched)
                 active = [qs for qs in active if id(qs) not in done and qs.T]
                 demand = self._demands(active, agg) if active else None
-        self._quant_finalize(pending, agg)
+        self._quant_finalize(self._quant_scan(deferred, scan_rounds, agg), agg)
         for qs in states:
             qs.I.commit()
 
@@ -1226,76 +1247,38 @@ class ECPIndex:
         }
         return key_rows, keys, leaf_keys, fp_keys, missing
 
-    # ------------------------------------------- quantized leaf scan round
-    def _quant_launch(self, leaf_keys, key_rows, qpayloads, k, agg):
-        """Scan every (query, leaf) unit of one traversal round from the
-        quantized companion blocks with ONE grouped device launch.
-        Returns what ``_quant_record`` takes, or None when no leaf has rows.
+    # ------------------------------------------------ quantized leaf scan
+    def _quant_record(
+        self, leaf_keys, key_rows, qpayloads, leaf_ids, tomb, k, leaf_cnt, done, deferred
+    ) -> bool:
+        """The control-flow half of one round's quantized leaf scans.
+        Returns whether the round had a unit to scan.
 
-        Only the approximate results are produced here — they go on
-        ``pending`` and are reranked once, at the end of the increment
-        (``_quant_finalize``), when every scanned leaf's upper bounds have
-        been seen and the per-query pruning watermark is as tight as it
-        will get.  Traversal control flow never looks at staged leaf
-        distances (only at the virtual candidate count and the internal
-        levels), so deferring the rerank cannot change which nodes are
-        visited."""
-        info = self.info
-        ops = _kernel_ops()
-        with span("ecp.pack", agg, "pack_s"):
-            units = []  # (qs, key, qn, R)
-            for key in leaf_keys:
-                qn = qpayloads[key]
-                if qn.n_rows == 0:
-                    continue  # matches the fp engines: empty nodes cost nothing
-                for qs, _leaf, _lv, _nd in key_rows[key]:
-                    units.append(
-                        (qs, key, qn, max(self._rerank_depth or 0, qs.emitted + k))
-                    )
-            if not units:
-                return None
-            G = len(units)
-            n_max = max(u[2].n_rows for u in units)
-            r_max = max(u[3] for u in units)
-            kop = min(n_max, -(-(r_max + 16) // 32) * 32)
-            q_arr = np.stack([np.asarray(u[0].q, np.float32) for u in units])
-            codes = np.zeros((G, n_max, info.dim), qdtype(self._qformat))
-            scales = np.zeros(G, np.float32)
-            offsets = np.zeros(G, np.float32)
-            n_rows = np.zeros(G, np.int32)
-            for g, (qs, key, qn, R) in enumerate(units):
-                codes[g, : qn.n_rows] = qn.codes
-                scales[g] = qn.scale
-                offsets[g] = qn.offset
-                n_rows[g] = qn.n_rows
-        # ids of the leaves whose dead rows (tombstones, excluded ids) the
-        # recording filters
-        tomb = self._tomb_sorted()
-        leaf_ids = [None] * G
-        if tomb is not None or any(u[0].exclude for u in units):
-            with span("ecp.fetch", agg, "fetch_s"):
-                for g, (qs, key, _, _) in enumerate(units):
-                    if tomb is not None or qs.exclude:
-                        leaf_ids[g] = self._get_leaf_ids(*key)
-        # ---- the round's single grouped kernel launch
-        with span("ecp.kernel", agg, "kernel_s"):
-            dists, idxs = ops.grouped_distance_topk(
-                q_arr, codes, scales, offsets, n_rows, kop, info.metric, self._qformat
-            )
-        agg.kernel_launches += 1
-        agg.kernel_groups += G
-        agg.kernel_rows += int(n_rows.sum())
-        agg.h2d_bytes += ops.grouped_launch_bytes(G, n_max, info.dim, codes.dtype.itemsize)
-        return units, dists, idxs, kop, leaf_ids, tomb
-
-    def _quant_record(self, units, dists, idxs, kop, leaf_ids, tomb, k, leaf_cnt, done, pending) -> None:
-        """Record one launch's approximate results on ``pending`` and
-        advance each unit's query control flow."""
-        for g, (qs, key, qn, R) in enumerate(units):
+        Appends the round's (query, leaf) units to ``deferred`` as
+        ``(qs, key, qn, R, kop, dead_rows)``, where ``kop`` is the top-k
+        width a launch of the round's units would ask for, and advances
+        each unit's query as the fp engine's staging would.  Traversal
+        control flow never looks at a scanned leaf's distances, only at
+        the virtual candidate count (every live row of the leaf) and the
+        internal levels, so the scans wait for the end of the increment
+        (``_quant_scan``) without changing which nodes are visited."""
+        units = []  # (qs, key, qn, R)
+        for key in leaf_keys:
+            qn = qpayloads[key]
+            if qn.n_rows == 0:
+                continue  # matches the fp engines: empty nodes cost nothing
+            for qs, _leaf, _lv, _nd in key_rows[key]:
+                units.append((qs, key, qn, max(self._rerank_depth or 0, qs.emitted + k)))
+        if not units:
+            return False
+        n_max = max(u[2].n_rows for u in units)
+        r_max = max(u[3] for u in units)
+        kop = min(n_max, -(-(r_max + 16) // 32) * 32)
+        for qs, key, qn, R in units:
             dead_rows = None
             n_dead = 0
             if tomb is not None or qs.exclude:
-                ids = leaf_ids[g]
+                ids = leaf_ids[key]
                 dead = np.zeros(len(ids), bool)
                 if tomb is not None:
                     dead |= np.isin(ids, tomb)
@@ -1303,19 +1286,7 @@ class ECPIndex:
                     dead |= np.isin(ids, qs.excl())
                 dead_rows = np.flatnonzero(dead)
                 n_dead = len(dead_rows)
-            valid = idxs[g] >= 0
-            pending.append(
-                (
-                    qs,
-                    key,
-                    qn,
-                    R,
-                    dists[g][valid].astype(np.float64),
-                    idxs[g][valid].astype(np.int64),
-                    qn.n_rows > kop,
-                    dead_rows,
-                )
-            )
+            deferred.append((qs, key, qn, R, kop, dead_rows))
             qs.stats.distance_calcs += qn.n_rows
             qs.stats.leaves_opened += 1
             # virtual candidate count advances by what the fp engine would
@@ -1331,6 +1302,95 @@ class ECPIndex:
                     qs.b_cur *= 2
                 else:
                     done.add(id(qs))
+        return True
+
+    def _quant_scan(self, units, rounds, agg) -> list:
+        """The results half: scan an increment's deferred units in a few
+        grouped launches and return ``_quant_finalize``'s pending entries,
+        in round order.
+
+        The units go to the kernel sorted by rows, largest first, in
+        consecutive launches of ``_launch_width`` groups, so a launch pads
+        little past its own leaves.  Each unit's top-k list is cut back to
+        its round's ``kop``: a group's list depends only on its own codes,
+        and the kernel orders by (distance, row), so a wider list's prefix
+        is the narrower one and every entry is what a launch of the
+        unit's round alone gave."""
+        if not units:
+            return []
+        G = self._launch_width(len(units), rounds)
+        order = sorted(range(len(units)), key=lambda u: -units[u][2].n_rows)
+        found: list = [None] * len(units)
+        for start in range(0, len(units), G):
+            chunk = order[start : start + G]
+            dists, idxs = self._quant_launch([units[u] for u in chunk], agg)
+            for g, u in enumerate(chunk):
+                found[u] = (dists[g], idxs[g])
+        pending = []
+        with span("ecp.expand", agg, "expand_s"):
+            for (qs, key, qn, R, kop, dead_rows), (d, i) in zip(units, found):
+                d, i = d[:kop], i[:kop]
+                valid = i >= 0
+                pending.append(
+                    (
+                        qs,
+                        key,
+                        qn,
+                        R,
+                        d[valid].astype(np.float64),
+                        i[valid].astype(np.int64),
+                        qn.n_rows > kop,
+                        dead_rows,
+                    )
+                )
+        return pending
+
+    @staticmethod
+    def _launch_width(n_units: int, rounds: int) -> int:
+        """Groups per launch for an increment of ``n_units`` scan units
+        over ``rounds`` leaf-bearing rounds: ``ceil(n_units / 64)`` as a
+        power of two, at least the kernel wrapper's floor of
+        ``MIN_GROUPS`` (8) and at most 128, so a query at b=64 launches
+        about 8 times and each launch runs the program a one-group warm-up
+        call at its largest leaf compiled.  Widened, where needed, to the
+        rounds' average unit count as a power of two, so an increment never
+        launches more often than it had rounds; that passes 128 only where
+        the rounds averaged more than 128 units (a batch of more than 128
+        queries, as wide as the per-round launch it replaces)."""
+        G = min(128, max(_kernel_ops().GROUPED_MIN_GROUPS, _pow2(-(-n_units // 64))))
+        return max(G, _pow2(-(-n_units // rounds)))
+
+    def _quant_launch(self, units, agg):
+        """One grouped device launch over ``units``, a chunk of
+        ``_quant_scan``'s: their codes packed to ``[G, n_max, D]`` at the
+        widest ``kop`` any of their rounds asked for (capped at the unit's
+        rows; for the units of one query, ``min(n_max, round32(R + 16))``
+        over the launch).  Returns the kernel's numpy (dists, idxs)."""
+        info = self.info
+        ops = _kernel_ops()
+        with span("ecp.pack", agg, "pack_s"):
+            G = len(units)
+            n_max = max(u[2].n_rows for u in units)
+            kop = max(min(u[4], u[2].n_rows) for u in units)
+            q_arr = np.stack([np.asarray(u[0].q, np.float32) for u in units])
+            codes = np.zeros((G, n_max, info.dim), qdtype(self._qformat))
+            scales = np.zeros(G, np.float32)
+            offsets = np.zeros(G, np.float32)
+            n_rows = np.zeros(G, np.int32)
+            for g, (_, _, qn, *_) in enumerate(units):
+                codes[g, : qn.n_rows] = qn.codes
+                scales[g] = qn.scale
+                offsets[g] = qn.offset
+                n_rows[g] = qn.n_rows
+        with span("ecp.kernel", agg, "kernel_s"):
+            dists, idxs = ops.grouped_distance_topk(
+                q_arr, codes, scales, offsets, n_rows, kop, info.metric, self._qformat
+            )
+        agg.kernel_launches += 1
+        agg.kernel_groups += G
+        agg.kernel_rows += int(n_rows.sum())
+        agg.h2d_bytes += ops.grouped_launch_bytes(G, n_max, info.dim, codes.dtype.itemsize)
+        return dists, idxs
 
     def _quant_finalize(self, pending, agg: SearchStats) -> None:
         """End-of-increment rerank of every pending (query, leaf) unit.

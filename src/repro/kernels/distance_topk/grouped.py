@@ -1,11 +1,12 @@
 """Pallas TPU kernel: grouped quantized distance + running top-k.
 
-The batched traversal's per-round launch (core/search.py): each group g
-is one (query, leaf) scan unit — query row ``q[g]`` against that leaf's
-quantized codes — and ALL units in a round go up in a single
-``pallas_call`` instead of one kernel launch per leaf.  Groups are
-independent (grid axis 0 is parallel); the candidate axis reuses the
-running-top-k scratch pattern of ``distance_topk``.
+The quantized traversal's leaf scan (core/search.py): each group g is one
+(query, leaf) scan unit — query row ``q[g]`` against that leaf's
+quantized codes — and an increment's units go up, sorted by rows, in a
+few ``pallas_call`` launches of up to 128 groups instead of one launch
+per leaf.  Groups are independent (grid axis 0 is parallel); the
+candidate axis reuses the running-top-k scratch pattern of
+``distance_topk``.
 
 Inputs are padded to a common leaf size: codes [G, N_pad, D] in the
 quantized dtype (int8 | float16), per-group dequant params (scale,
@@ -20,9 +21,12 @@ Every per-group array carries a leading group axis that the BlockSpecs
 squeeze (``None``), so each block's last two dims equal the array's —
 what the TPU's (8, 128) tiling rule requires of a one-row block.
 
-The host wrapper pads N to a multiple of ``bn`` and G to a power of two
-before the jitted call: a traversal round's leaf sizes and unit counts
-vary, and each distinct shape would otherwise be a fresh compile.
+The host wrapper pads N to a multiple of ``bn`` and G to a power of two,
+at least ``MIN_GROUPS`` (8), before the jitted call: leaf sizes and unit
+counts vary, and each distinct shape would otherwise be a fresh compile.
+The floor is the search's usual launch (a single query's increment
+launches 8 groups at a time), so a warm-up call of one group at a leaf's
+size compiles the very program the search's launches at that size run.
 """
 from __future__ import annotations
 
@@ -38,12 +42,18 @@ from .distance_topk import _merge_topk
 
 NEG_ONE = -1
 BN = 128  # candidate rows per grid step
+# the fewest groups a launch is padded to; a query's increment launches
+# this many at a time (core/search.py), so a one-group warm-up call at a
+# leaf's size compiles the program those launches run
+MIN_GROUPS = 8
 
 
 def padded_shape(G: int, N: int, bn: int = BN) -> tuple[int, int]:
     """The (groups, rows) a launch of G groups of at most N rows is padded
-    to: G to a power of two, N to a multiple of ``bn``."""
-    return 1 << max(G - 1, 0).bit_length(), -(-max(N, 1) // bn) * bn
+    to: G to a power of two and at least ``MIN_GROUPS``, so one warm-up
+    group covers a search's launches of up to that many, and N to a
+    multiple of ``bn``."""
+    return max(MIN_GROUPS, 1 << max(G - 1, 0).bit_length()), -(-max(N, 1) // bn) * bn
 
 
 def launch_bytes(G: int, N: int, D: int, code_itemsize: int, bn: int = BN) -> int:
@@ -164,8 +174,9 @@ def grouped_distance_topk_pallas(
     interpret: bool = False,
 ):
     """q [G, D], codes [G, N, D] (int8|f16), scales/offsets [G],
-    n_rows [G] -> (dists [G, k] f32, idx [G, k] i32) ascending; rows past
-    each group's n_rows come back as (inf, -1)."""
+    n_rows [G] -> numpy (dists [G, k] f32, idx [G, k] i32) ascending; rows
+    past each group's n_rows come back as (inf, -1).  Both results come
+    back in one transfer and are cut to G on the host."""
     q = np.asarray(q, np.float32)
     codes = np.asarray(codes)
     G, N, D = codes.shape
@@ -181,5 +192,7 @@ def grouped_distance_topk_pallas(
     prm[:G, 0, 1] = offsets
     nr = np.zeros((G_pad, 1, 1), np.int32)  # pad groups have no valid rows
     nr[:G, 0, 0] = n_rows
-    out_d, out_i = _grouped_call(q3, codes, prm, nr, k, metric, qformat, bn, interpret)
+    out_d, out_i = jax.device_get(
+        _grouped_call(q3, codes, prm, nr, k, metric, qformat, bn, interpret)
+    )
     return out_d[:G], out_i[:G]

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 
 from .distance_topk import distance_topk_pallas
+from .grouped import MIN_GROUPS as GROUPED_MIN_GROUPS  # noqa: F401  (a launch's fewest groups)
 from .grouped import grouped_distance_topk_pallas
 from .grouped import launch_bytes as grouped_launch_bytes  # noqa: F401  (a launch's bytes)
 from .ref import distance_topk_ref, grouped_distance_topk_ref
@@ -52,27 +53,21 @@ def grouped_distance_topk(
     impl: str = "auto",
     **kw,
 ):
-    """One device launch for a whole traversal round: group g scores
-    q[g] against its leaf's quantized codes[g].  Returns numpy
+    """One device launch for many (query, leaf) scan units: group g
+    scores q[g] against its leaf's quantized codes[g].  Returns numpy
     (dists [G, k], idx [G, k]); invalid tail entries are (inf, -1).
 
     impl: "auto" | "ref" | "pallas" | "pallas_interpret"
     """
-    import numpy as np
-
     impl = resolve_impl(impl)
     if impl == "ref":
-        d, i = grouped_distance_topk_ref(
-            q, codes, scales, offsets, n_rows, k, metric, qformat
-        )
-    elif impl == "pallas":
-        d, i = grouped_distance_topk_pallas(
+        return grouped_distance_topk_ref(q, codes, scales, offsets, n_rows, k, metric, qformat)
+    if impl == "pallas":
+        return grouped_distance_topk_pallas(
             q, codes, scales, offsets, n_rows, k, metric, qformat, **kw
         )
-    elif impl == "pallas_interpret":
-        d, i = grouped_distance_topk_pallas(
+    if impl == "pallas_interpret":
+        return grouped_distance_topk_pallas(
             q, codes, scales, offsets, n_rows, k, metric, qformat, interpret=True, **kw
         )
-    else:
-        raise ValueError(f"unknown impl {impl!r}")
-    return np.asarray(d), np.asarray(i)
+    raise ValueError(f"unknown impl {impl!r}")

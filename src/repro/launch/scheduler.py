@@ -263,6 +263,42 @@ class _Req:
 
 _STOP = object()
 
+# numpy's BLAS runs on one thread while any scheduler is up: a search's
+# GEMMs are small (one query row against a leaf's rows), and OpenBLAS's
+# default pool of one thread per core spins between calls.  Four workers'
+# reranks kept 7-9 of a 13-core TPU v5e host's cores busy in those threads,
+# starving the workers themselves and the device runtime's threads; one
+# BLAS thread per call lets the workers run side by side instead.
+_blas_lock = threading.Lock()
+_blas_users = 0
+_blas_limits = None  # threadpoolctl's handle, to restore the process's own limits
+
+
+def _hold_one_blas_thread() -> None:
+    """Count one more scheduler up; the first caps BLAS at one thread
+    (when threadpoolctl is there to reach the BLAS library)."""
+    global _blas_users, _blas_limits
+    with _blas_lock:
+        if _blas_users == 0:
+            try:
+                from threadpoolctl import threadpool_limits
+            except ImportError:  # no handle on the BLAS pool: leave it as it is
+                pass
+            else:
+                _blas_limits = threadpool_limits(limits=1, user_api="blas")
+        _blas_users += 1
+
+
+def _release_blas_threads() -> None:
+    """Count one scheduler down; the last restores the limits the process
+    had before the first."""
+    global _blas_users, _blas_limits
+    with _blas_lock:
+        _blas_users -= 1
+        if _blas_users == 0 and _blas_limits is not None:
+            _blas_limits.restore_original_limits()
+            _blas_limits = None
+
 
 class RequestScheduler:
     """Thread-pool searches over an index, with bounded admission and
@@ -275,7 +311,9 @@ class RequestScheduler:
     the mutation runs concurrently with reads (they hold snapshots) and
     the manager re-pins afterwards; with fstore it takes the writer side
     of a RW lock.  ``read_lock()`` brackets non-snapshot reads (query
-    continuations) in RW-lock mode and is free otherwise.
+    continuations) in RW-lock mode and is free otherwise.  While any
+    scheduler is up, numpy's BLAS runs on one thread (``shutdown`` gives
+    the process its own limits back).
     """
 
     def __init__(
@@ -306,6 +344,8 @@ class RequestScheduler:
         self._rw = _RWLock()
         self.queue_depth = int(queue_depth)
         self._q: "queue.Queue" = queue.Queue(maxsize=self.queue_depth)
+        _hold_one_blas_thread()
+        self._holds_blas = True
         self._threads = [
             threading.Thread(target=self._worker, name=f"serve-worker-{i}", daemon=True)
             for i in range(max(1, int(workers)))
@@ -433,13 +473,16 @@ class RequestScheduler:
 
     # ------------------------------------------------------------ lifecycle
     def shutdown(self) -> None:
-        """Drain queued requests, stop the workers, drop the cached
-        snapshot.  Idempotent."""
+        """Drain queued requests, stop the workers, give back the BLAS
+        threads, drop the cached snapshot.  Idempotent."""
         for _ in self._threads:
             self._q.put(_STOP)
         for t in self._threads:
             t.join()
         self._threads = []
+        if self._holds_blas:
+            self._holds_blas = False
+            _release_blas_threads()
         if self.snapshots is not None:
             self.snapshots.close()
 

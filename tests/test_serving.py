@@ -326,6 +326,38 @@ def test_scheduler_worker_error_propagates():
     sched.shutdown()
 
 
+def test_scheduler_holds_blas_at_one_thread_while_up():
+    """Workers run numpy's BLAS on one thread while any scheduler is up;
+    the last scheduler's shutdown restores the process's own limits."""
+    threadpoolctl = pytest.importorskip("threadpoolctl")
+
+    def blas_threads():
+        return sorted(
+            i["num_threads"] for i in threadpoolctl.threadpool_info() if i["user_api"] == "blas"
+        )
+
+    np.ones((2, 2)) @ np.ones((2, 2))  # load numpy's BLAS
+    own = blas_threads()
+    assert own
+    seen = []
+
+    class Probe:
+        def search(self, q, k, b=None, **o):
+            seen.append(blas_threads())
+            return None
+
+    a = RequestScheduler(Probe(), workers=2, queue_depth=4)
+    b = RequestScheduler(Probe(), workers=1, queue_depth=4)
+    a.search(np.zeros(4), 5)
+    a.shutdown()
+    a.shutdown()  # idempotent: gives back its hold once
+    b.search(np.zeros(4), 5)  # b is still up: still one thread
+    assert blas_threads() == [1] * len(own)
+    b.shutdown()
+    assert seen == [[1] * len(own)] * 2
+    assert blas_threads() == own
+
+
 def test_scheduler_mutate_serializes_with_rwlock_reads():
     """Non-pinning searcher: mutate() must be exclusive with in-flight
     reads (the fstore fallback path)."""

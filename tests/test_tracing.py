@@ -151,7 +151,10 @@ def test_kernel_counters_match_the_launches(blob, data):
     idx.close()
 
 
-@pytest.mark.parametrize("G,N,padded", [(1, 1, (1, 128)), (3, 128, (4, 128)), (5, 129, (8, 256)), (8, 455, (8, 512))])
+@pytest.mark.parametrize(
+    "G,N,padded",
+    [(1, 1, (8, 128)), (3, 128, (8, 128)), (5, 129, (8, 256)), (8, 455, (8, 512)), (9, 455, (16, 512))],
+)
 def test_launch_bytes_follow_the_wrappers_padding(G, N, padded):
     assert grouped.padded_shape(G, N) == padded
     g, n = padded
